@@ -149,11 +149,16 @@ def render(path, records):
 
     phases = summary.get("phases") or {}
     if phases:
-        print("\nphase breakdown:")
-        total = sum(p["seconds"] for p in phases.values()) or 1.0
-        for name, p in sorted(phases.items(), key=lambda kv: -kv[1]["seconds"]):
-            print(f"  {name:<16} {p['seconds']*1e3:9.1f}ms  "
-                  f"x{p['count']:<5d} {100 * p['seconds'] / total:5.1f}%")
+        # shares are of self time (a span less its child spans), so nested
+        # phases count each second once; older streams carry no self time
+        own = {k: p.get("self_seconds", p["seconds"])
+               for k, p in phases.items()}
+        print("\nphase breakdown (total, self, count, share of self):")
+        total = sum(own.values()) or 1.0
+        for name, p in sorted(phases.items(), key=lambda kv: -own[kv[0]]):
+            print(f"  {name:<16} {p['seconds']*1e3:9.1f}ms "
+                  f"{own[name]*1e3:9.1f}ms  "
+                  f"x{p['count']:<5d} {100 * own[name] / total:5.1f}%")
 
     if stats:
         cols = {}

@@ -81,6 +81,10 @@ def main():
     ap.add_argument("--metrics-every", type=int, default=8,
                     help="flush buffered request/tick records every K "
                          "engine ticks")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="dump a TensorBoard-viewable jax.profiler trace "
+                         "of the run to this directory: the engine's "
+                         "serve.* spans on the device trace's clock")
     args = ap.parse_args()
 
     cfg = get_arch(args.arch)
@@ -104,7 +108,8 @@ def main():
                              cfg.dtype)
         print("no --ckpt: serving seed-initialized params")
 
-    tele = make_telemetry(args.metrics_out, args.metrics_every)
+    tele = make_telemetry(args.metrics_out, args.metrics_every,
+                          profile_dir=args.profile)
     tele.manifest(config=vars(args), seed=args.seed)
     try:
         engine = Engine(cfg, params, slots=args.slots, max_len=args.max_len,

@@ -7,7 +7,7 @@ bytes on the wire, staleness). This module gives them ONE schema-versioned
 stream instead of ad-hoc lists and print blocks:
 
   * a :class:`Telemetry` bus with pluggable sinks (:class:`JsonlSink`,
-    :class:`StdoutSink`, :class:`MemorySink`) emitting a run **manifest**
+    :class:`MemorySink`) emitting a run **manifest**
     (:func:`run_manifest`: config, git SHA, jax version, device topology,
     seed) followed by per-round ``round`` records, device-drained ``stats``
     records and a closing ``summary``;
@@ -30,8 +30,10 @@ Record kinds (one JSON object per line in a metrics JSONL):
   stats      a drained on-device accumulator window
              (``repro.obs.devstats``): ``round_start`` + one list per
              scalar field, one host transfer per ``metrics_every`` rounds
-  summary    aggregates at close: steady rounds/sec, phase span totals,
-             wire totals, staleness histogram when the run recorded one
+  summary    aggregates at close: steady rounds/sec, phase span totals
+             (``seconds`` and ``self_seconds``, the time outside child
+             spans), wire totals, staleness histogram when the run
+             recorded one
 
 ``scripts/report.py`` renders (or ``--check`` validates) any such stream;
 the schema spec lives in docs/observability.md. Telemetry is strictly
@@ -78,19 +80,6 @@ class JsonlSink:
 
     def close(self) -> None:
         self._f.close()
-
-
-class StdoutSink:
-    """Print each record as one JSON line (debugging / piping)."""
-
-    def write(self, record: Dict[str, Any]) -> None:
-        print(json.dumps(record), flush=True)
-
-    def flush(self) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
 
 
 class MemorySink:
@@ -174,13 +163,15 @@ class Span:
     ``jax.profiler.TraceAnnotation`` so the phase shows up as a named
     region in a profiler trace. Fence async work INSIDE the span (either
     explicitly or via :meth:`fence`) so the timer measures completion, not
-    dispatch."""
+    dispatch. Spans nest: each adds its time to the enclosing open span's
+    ``_child``, which that span's self time leaves out."""
 
-    __slots__ = ("name", "_tele", "_ann", "_t0")
+    __slots__ = ("name", "_tele", "_ann", "_t0", "_child")
 
     def __init__(self, name: str, tele: "Telemetry"):
         self.name = name
         self._tele = tele
+        self._child = 0.0
 
     def fence(self, x):
         """``jax.block_until_ready`` passthrough — the phase ends when the
@@ -192,13 +183,14 @@ class Span:
         import jax
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
+        self._tele._open.append(self)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
-        self._tele._note_span(self.name, dt)
+        self._tele._close_span(self, dt)
         return False
 
 
@@ -245,7 +237,9 @@ class Telemetry:
         self.consensus = consensus
         self.profile_dir = profile_dir
         self._buf: List[Dict[str, Any]] = []
-        self._phases: Dict[str, List[float]] = {}   # name -> [count, secs]
+        # name -> [count, secs, self secs]
+        self._phases: Dict[str, List[float]] = {}
+        self._open: List[Span] = []     # spans entered and not yet exited
         self._rounds = 0
         self._round_seconds: List[float] = []
         self._last: Dict[str, Any] = {}
@@ -320,15 +314,20 @@ class Telemetry:
     def span(self, name: str) -> Span:
         return Span(name, self)
 
-    def _note_span(self, name: str, dt: float) -> None:
-        agg = self._phases.setdefault(name, [0, 0.0])
+    def _close_span(self, span: Span, dt: float) -> None:
+        self._open.pop()
+        if self._open:
+            self._open[-1]._child += dt
+        agg = self._phases.setdefault(span.name, [0, 0.0, 0.0])
         agg[0] += 1
         agg[1] += dt
+        agg[2] += dt - span._child
 
     @property
     def phase_totals(self) -> Dict[str, Dict[str, float]]:
-        return {k: {"count": int(c), "seconds": round(s, 6)}
-                for k, (c, s) in sorted(self._phases.items())}
+        return {k: {"count": int(c), "seconds": round(s, 6),
+                    "self_seconds": round(own, 6)}
+                for k, (c, s, own) in sorted(self._phases.items())}
 
     # ------------------------------------------------------------ lifecycle
 
@@ -416,16 +415,11 @@ NULL = NullTelemetry()
 def make_telemetry(metrics_out: Optional[str] = None,
                    metrics_every: int = 8,
                    profile_dir: Optional[str] = None,
-                   consensus: bool = False,
-                   stdout: bool = False) -> Telemetry:
+                   consensus: bool = False) -> Telemetry:
     """The launcher-facing constructor: a JSONL sink when ``metrics_out``
-    is set, a stdout sink on request, profiling when ``profile_dir`` is
-    set. With nothing enabled the bus still aggregates spans (so phase
-    totals can be printed) at negligible cost."""
-    sinks = []
-    if metrics_out:
-        sinks.append(JsonlSink(metrics_out))
-    if stdout:
-        sinks.append(StdoutSink())
+    is set, profiling when ``profile_dir`` is set. With nothing enabled
+    the bus still aggregates spans (so phase totals can be printed) at
+    negligible cost."""
+    sinks = [JsonlSink(metrics_out)] if metrics_out else []
     return Telemetry(sinks, metrics_every=metrics_every,
                      profile_dir=profile_dir, consensus=consensus)

@@ -213,7 +213,9 @@ class Engine:
         if self._quantize is not None:
             row = self._quantize(row)
         self._pool = self._scatter(self._pool, row, jnp.int32(slot))
-        first = int(jax.device_get(self._argmax(logits))[0])
+        ids = self._argmax(logits)
+        with self.tele.span("serve.sync"):
+            first = int(jax.device_get(ids)[0])
         plen = int(np.shape(req.tokens)[-1])
         now = self.now()
         self._occupant[slot] = req
@@ -250,36 +252,50 @@ class Engine:
 
     def step(self) -> List[Completion]:
         """One scheduler tick: admit into free slots, then ONE shared decode
-        over every active slot. Returns the requests that completed."""
+        over every active slot. Returns the requests that completed.
+
+        Spans (``docs/serving.md``): ``serve.tick`` holds the whole tick;
+        in it each admission's ``serve.admit`` (holding ``serve.prefill``
+        and the first token's ``serve.sync``), the ``serve.decode``
+        dispatch (holding the next tokens' ``serve.sync``) and the
+        ``serve.retire`` loop."""
+        tele = self.tele
         completed: List[Completion] = []
         admitted = 0
-        while self._queue and self._free:
-            self._admit(self._queue.popleft(), self._free.pop(), completed)
-            admitted += 1
-        active = [s for s in range(self.slots)
-                  if self._occupant[s] is not None]
-        if active:
-            with self.tele.span("serve.decode"):
-                logits, self._pool = self._decode(
-                    self.params, self._pool,
-                    jnp.asarray(self._last_tok[:, None]),
-                    jnp.asarray(np.maximum(self._pos, 1)))
-                nxt = np.asarray(jax.device_get(self._argmax(logits)))
-            for s in active:
-                tok = int(nxt[s])
-                self._out[self._occupant[s].rid].append(tok)
-                self._pos[s] += 1
-                self._last_tok[s] = tok
-                self._budget[s] -= 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    self._retire(s, "eos", completed)
-                elif self._budget[s] <= 0:
-                    self._retire(s, "length", completed)
-                elif self._pos[s] >= self.max_len:
-                    self._retire(s, "capacity", completed)
-        self._ticks += 1
-        self.tele.tick(self._ticks - 1, active=len(active), admitted=admitted,
-                       completed=len(completed), queue_depth=len(self._queue))
+        with tele.span("serve.tick"):
+            while self._queue and self._free:
+                with tele.span("serve.admit"):
+                    self._admit(self._queue.popleft(), self._free.pop(),
+                                completed)
+                admitted += 1
+            active = [s for s in range(self.slots)
+                      if self._occupant[s] is not None]
+            if active:
+                with tele.span("serve.decode"):
+                    logits, self._pool = self._decode(
+                        self.params, self._pool,
+                        jnp.asarray(self._last_tok[:, None]),
+                        jnp.asarray(np.maximum(self._pos, 1)))
+                    ids = self._argmax(logits)
+                    with tele.span("serve.sync"):
+                        nxt = np.asarray(jax.device_get(ids))
+                with tele.span("serve.retire"):
+                    for s in active:
+                        tok = int(nxt[s])
+                        self._out[self._occupant[s].rid].append(tok)
+                        self._pos[s] += 1
+                        self._last_tok[s] = tok
+                        self._budget[s] -= 1
+                        if self.eos_id is not None and tok == self.eos_id:
+                            self._retire(s, "eos", completed)
+                        elif self._budget[s] <= 0:
+                            self._retire(s, "length", completed)
+                        elif self._pos[s] >= self.max_len:
+                            self._retire(s, "capacity", completed)
+            self._ticks += 1
+            tele.tick(self._ticks - 1, active=len(active),
+                      admitted=admitted, completed=len(completed),
+                      queue_depth=len(self._queue))
         return completed
 
     def run(self, requests=None) -> List[Completion]:

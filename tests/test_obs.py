@@ -333,6 +333,35 @@ def test_jsonl_chunked_drain_report_check(tmp_path):
     assert "report: OK" in chk.stdout
 
 
+def test_phase_self_time_on_nested_spans(tmp_path, monkeypatch):
+    """A span's ``self_seconds`` is its time less its child spans', so the
+    self times of a tree add up to its root's time once; report.py shows
+    both columns."""
+    from repro.obs import telemetry
+    out = tmp_path / "nested.jsonl"
+    tele = Telemetry([JsonlSink(str(out))])
+    tele.manifest(config={}, seed=0)
+    clock = iter([0.0, 1.0, 4.0, 5.0, 6.0, 8.0, 10.0, 12.0])
+    monkeypatch.setattr(telemetry.time, "perf_counter", lambda: next(clock))
+    with tele.span("outer"):            # 0 .. 12
+        with tele.span("inner"):        # 1 .. 4
+            pass
+        with tele.span("inner"):        # 5 .. 10
+            with tele.span("leaf"):     # 6 .. 8
+                pass
+    monkeypatch.undo()
+    assert tele.summary()["phases"] == {
+        "inner": {"count": 2, "seconds": 8.0, "self_seconds": 6.0},
+        "leaf": {"count": 1, "seconds": 2.0, "self_seconds": 2.0},
+        "outer": {"count": 1, "seconds": 12.0, "self_seconds": 4.0}}
+    tele.close()
+    ren = subprocess.run([sys.executable, "scripts/report.py", str(out)],
+                         cwd=ROOT, capture_output=True, text=True)
+    assert ren.returncode == 0, ren.stderr
+    line = next(x for x in ren.stdout.splitlines() if "outer" in x).split()
+    assert line[1:3] == ["12000.0ms", "4000.0ms"] and line[-1] == "33.3%"
+
+
 def test_report_check_rejects_malformed_stream(tmp_path):
     bad = tmp_path / "bad.jsonl"
     # no manifest, unknown kind, stats with ragged columns

@@ -18,7 +18,9 @@ The load-bearing pins:
 MoE runs with drop-free capacity (finite capacity legitimately makes
 token dropping depend on how many tokens share a dispatch).
 """
+import contextlib
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,7 @@ from repro.fed.serve import build_serve_fns
 from repro.models import init_params, model_specs
 from repro.serve import (Engine, LoadSpec, Request, generate_requests,
                          load_serve_params)
+from repro.obs import NULL, MemorySink, Telemetry
 from repro.serve.engine import QUANT_FAMILIES
 
 FAMS = ["qwen1.5-4b",        # dense (MHA, qkv bias)
@@ -236,6 +239,118 @@ def test_kv_quant_rejects_stateful_families():
     cfg = _cfg("falcon-mamba-7b")
     with pytest.raises(ValueError, match="SSM state"):
         Engine(cfg, _params(cfg), slots=1, max_len=12, kv_quant=True)
+
+
+# ---------------------------------------------------------------- spans
+
+class _SpanLog:
+    """Telemetry hook that logs each span's opening and closing and each
+    tick record in call order, with the queue depth at every
+    ``serve.prefill``."""
+
+    def __init__(self, eng):
+        self.eng, self.log = eng, []
+
+    def __getattr__(self, name):
+        return getattr(NULL, name)
+
+    @contextlib.contextmanager
+    def span(self, name):
+        self.log.append(("open", name, self.eng.pending))
+        yield
+        self.log.append(("close", name, None))
+
+    def tick(self, tick, **fields):
+        self.log.append(("tick", tick, None))
+
+
+def _span_tree(log):
+    """[(name, children)] of the top-level spans; tick records become
+    leaves named "tick"."""
+    root, stack = [], []
+    for kind, name, _ in log:
+        if kind == "open":
+            node = (name, [])
+            (stack[-1][1] if stack else root).append(node)
+            stack.append(node)
+        elif kind == "close":
+            assert stack.pop()[0] == name
+        else:
+            (stack[-1][1] if stack else root).append(("tick", []))
+    assert not stack
+    return root
+
+
+def test_span_tree_per_tick():
+    """Every tick is one ``serve.tick`` holding, in order, one
+    ``serve.admit`` per admission (its ``serve.prefill``, then the first
+    token's ``serve.sync``), then, when a slot is active, ``serve.decode``
+    (holding the next tokens' ``serve.sync``) and ``serve.retire``; the tick
+    record closes it. Admissions follow submission order."""
+    cfg = _cfg("qwen1.5-4b")
+    eng = Engine(cfg, _params(cfg), slots=2, max_len=MAX_LEN)
+    reqs = _workload(cfg, n=5)
+    for r in reqs:
+        eng.submit(r)
+    eng.tele = rec = _SpanLog(eng)
+    steps = 0
+    while eng.has_work:
+        eng.step()
+        steps += 1
+    tree = _span_tree(rec.log)
+    assert [name for name, _ in tree] == ["serve.tick"] * steps
+    admits = 0
+    for _, kids in tree:
+        names = " ".join(name for name, _ in kids)
+        assert re.fullmatch(r"(serve\.admit )*(serve\.decode serve\.retire )?"
+                            r"tick", names), names
+        for name, sub in kids:
+            inner = [n for n, _ in sub]
+            want = {"serve.admit": ["serve.prefill", "serve.sync"],
+                    "serve.decode": ["serve.sync"]}.get(name, [])
+            assert inner == want and all(not s for _, s in sub), (name, sub)
+            admits += name == "serve.admit"
+    assert admits == len(reqs)
+    assert [q for kind, name, q in rec.log
+            if kind == "open" and name == "serve.prefill"] == \
+        list(range(len(reqs) - 1, -1, -1))
+
+
+def test_telemetry_keeps_tokens_and_host_syncs(monkeypatch):
+    """A live Telemetry bus serves the same tokens as NULL through the
+    same number of host syncs, and its phase totals count the span tree:
+    one tick a step, one admit and one prefill a request, a sync in every
+    admission and decode, one retire a decode."""
+    counts = {"device_get": 0, "block_until_ready": 0}
+    for fn in counts:
+        real = getattr(jax, fn)
+
+        def counted(*a, _fn=fn, _real=real, **k):
+            counts[_fn] += 1
+            return _real(*a, **k)
+        monkeypatch.setattr(jax, fn, counted)
+    cfg = _cfg("qwen1.5-4b")
+    eng = Engine(cfg, _params(cfg), slots=2, max_len=MAX_LEN)
+    reqs = _workload(cfg, n=5)
+    live = Telemetry([MemorySink()])
+    ran = []
+    for tele in (NULL, live):
+        eng.tele = tele
+        for fn in counts:
+            counts[fn] = 0
+        ran.append((_tokens(eng.run(reqs)), dict(counts), eng._ticks))
+    (want, syncs, t0), (got, syncs_on, t1) = ran
+    assert got == want
+    assert syncs_on == syncs and syncs["block_until_ready"] == 0
+    ph = live.summary()["phases"]
+    n = {k: v["count"] for k, v in ph.items()}
+    assert n["serve.tick"] == t1 - t0
+    assert n["serve.admit"] == n["serve.prefill"] == len(reqs)
+    assert n["serve.sync"] == n["serve.admit"] + n["serve.decode"]
+    assert n["serve.retire"] == n["serve.decode"]
+    assert syncs_on["device_get"] == n["serve.sync"]
+    tick = ph["serve.tick"]
+    assert 0 < tick["self_seconds"] < tick["seconds"]
 
 
 # -------------------------------------------------- lifecycle invariants
