@@ -2,9 +2,22 @@
 
 Cache layouts (leading dim scans over layers):
   dense/moe/vlm : {"k","v": [L,B,W,KV,Dh]}            W = window (ring) or max_len
+  int8 (quant)  : {"k","v": [L,B,KV,W,Dh] int8, "k_scale","v_scale": [L,B,KV,W] f32}
   ssm           : {"h": [L,B,di,N] f32, "conv": [L,B,cw-1,di]}
   hybrid        : mamba2 state + shared-attn KV [nseg,B,W,KV,Dh]
   encdec        : self KV [L,...] + cross KV [L,B,Senc,KV,Dh] (built at prefill)
+
+Batch is axis 1 of every leaf, so the serve engine scatters a prefilled row
+into any pool alike. The int8 pool is stored in the layout that
+``kernels.quant_decode`` reads (heads before positions), and decode carries
+it whole through the layer loop: each layer writes its new token in place
+at ``[layer, row, :, slot]`` and the kernel picks the layer's tiles out of
+the stacked pool by a prefetched layer index. No layer of the pool is
+sliced out, relaid, transposed or restacked, and no layer's weights are
+copied out of the stack (``_quant_decode_layers``). The decode program
+does not donate the pool (callers may reuse the pool they passed), so XLA
+copies it once into the loop's carry; that is the one whole-pool copy of
+a tick.
 
 ``pos`` is the number of tokens already in the cache; RoPE uses absolute
 positions, so ring buffers (sliding window) stay correct without rotation.
@@ -31,31 +44,35 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int,
                quant: bool = False) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """Returns (ShapeDtypeStruct pytree, logical-axes pytree).
 
-    ``quant=True``: int8 K/V with per-(token, head) f32 scales — halves the
-    cache's HBM footprint/traffic; dequantization is fused HBM->VMEM by
+    ``quant=True``: int8 K/V with per-(token, head) f32 scales, heads before
+    positions (the kernel's layout, module docstring) — halves the cache's
+    HBM footprint/traffic; dequantization is fused HBM->VMEM by
     ``kernels.quant_decode`` on TPU (the XLA reference path dequantizes one
-    layer slice at a time inside the scan)."""
+    layer slice at a time inside the layer loop)."""
     L = cfg.n_layers
     hd = cfg.resolved_head_dim if cfg.n_heads else 0
     w = min(window or max_len, max_len)
     spec: Dict[str, Any] = {}
     axes: Dict[str, Any] = {}
     kv_ax = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
-    kv_dtype = jnp.int8 if quant else dtype
 
     def sds(shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, dt)
 
     fam = cfg.family
     if fam in ("dense", "vlm", "moe", "encdec"):
-        kvs = (L, batch, w, cfg.n_kv_heads, hd)
-        spec["k"], spec["v"] = sds(kvs, kv_dtype), sds(kvs, kv_dtype)
-        axes["k"] = axes["v"] = kv_ax
         if quant:
-            scs = (L, batch, w, cfg.n_kv_heads)
-            spec["k_scale"] = sds(scs, jnp.float32)
-            spec["v_scale"] = sds(scs, jnp.float32)
-            axes["k_scale"] = axes["v_scale"] = kv_ax[:-1]
+            q_ax = ("layers", "batch", "kv_heads", "cache_seq", "head_dim")
+            kvs = (L, batch, cfg.n_kv_heads, w, hd)
+            spec["k"], spec["v"] = sds(kvs, jnp.int8), sds(kvs, jnp.int8)
+            axes["k"] = axes["v"] = q_ax
+            spec["k_scale"] = sds(kvs[:-1], jnp.float32)
+            spec["v_scale"] = sds(kvs[:-1], jnp.float32)
+            axes["k_scale"] = axes["v_scale"] = q_ax[:-1]
+        else:
+            kvs = (L, batch, w, cfg.n_kv_heads, hd)
+            spec["k"], spec["v"] = sds(kvs), sds(kvs)
+            axes["k"] = axes["v"] = kv_ax
     if fam == "encdec":
         ckvs = (L, batch, enc_len, cfg.n_kv_heads, hd)
         spec["ck"], spec["cv"] = sds(ckvs), sds(ckvs)
@@ -88,6 +105,18 @@ def init_cache(cfg, batch, max_len, window=None, enc_len=0, dtype=jnp.bfloat16,
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
 
 
+def quantize_cache(cache):
+    """Full-precision attention cache [L,B,W,KV,Dh] -> the int8 pool's layout
+    [L,B,KV,W,Dh], k/v quantized per (token, head) with scales [L,B,KV,W];
+    other leaves (encdec's cross ck/cv) stay as they are."""
+    from repro.kernels.quant_decode import quantize_kv
+    out = dict(cache)
+    for name in ("k", "v"):
+        out[name], out[name + "_scale"] = quantize_kv(
+            jnp.swapaxes(cache[name], 2, 3))
+    return out
+
+
 # ------------------------------------------------------------------ helpers
 
 def _qkv(cfg, p, hn, prefix=""):
@@ -99,61 +128,81 @@ def _qkv(cfg, p, hn, prefix=""):
     return q, k, v
 
 
-def _attn_decode_block(cfg, p, h, ck, cv, pos, window, prefix="",
-                       scales=None, kv_kernel="xla"):
-    """One-token self-attention vs cache. h: [B,1,d]. Returns h', new (ck, cv)
-    [, new scales]. ``scales``: (k_scale, v_scale) when the cache is int8.
-
-    ``pos`` is a scalar shared by every row or a ``[B]`` vector of per-row
-    positions (continuous batching). ``kv_kernel`` selects the int8 attention
-    path: "xla" (reference dequant), "pallas" (fused HBM->VMEM dequant kernel)
-    or "interpret" (same kernel, Pallas interpret mode — CPU-safe).
-    """
-    from repro.kernels.quant_decode import quant_decode_attention, quantize_kv
-    b, w = ck.shape[0], ck.shape[1]
+def _rope_qkv(cfg, p, h, pos, prefix=""):
+    """Norm, q/k/v projections and RoPE of one token. h: [B,1,d]; pos
+    scalar or [B]. Returns q, k, v [B,1,heads,Dh] and pos as int32."""
     hn = rmsnorm(h, p[prefix + "ln_attn"], cfg.norm_eps)
     q, k, v = _qkv(cfg, p, hn, prefix)
     pos = jnp.asarray(pos, jnp.int32)
-    vec = pos.ndim == 1
-    posv = pos[:, None] if vec else jnp.full((1, 1), pos, jnp.int32)
+    posv = pos[:, None] if pos.ndim == 1 else jnp.full((1, 1), pos, jnp.int32)
     q = attn_lib.rope(q, posv, cfg.rope_theta)
     k = attn_lib.rope(k, posv, cfg.rope_theta)
+    return q, k, v, pos
+
+
+def _attn_decode_block(cfg, p, h, ck, cv, pos, window, prefix=""):
+    """One-token self-attention vs cache. h: [B,1,d]. Returns h', new (ck, cv).
+
+    ``pos`` is a scalar shared by every row or a ``[B]`` vector of per-row
+    positions (continuous batching).
+    """
+    b, w = ck.shape[0], ck.shape[1]
+    q, k, v, pos = _rope_qkv(cfg, p, h, pos, prefix)
     slot = pos % w if window else jnp.minimum(pos, w - 1)
 
     def write(buf, val):
         """Scatter one token per row at ``slot``. val: [B,1,...]."""
-        if vec:
+        if pos.ndim == 1:
             return buf.at[jnp.arange(b), slot].set(val[:, 0])
         return jax.lax.dynamic_update_slice_in_dim(buf, val, slot, axis=1)
 
-    if scales is not None:
-        ks, vs = scales
-        k8, ksc = quantize_kv(k)
-        v8, vsc = quantize_kv(v)
-        ck, cv = write(ck, k8), write(cv, v8)
-        ks, vs = write(ks, ksc), write(vs, vsc)
-        if kv_kernel != "xla" and window is None:
-            # Fused path: dequant happens HBM->VMEM inside the Pallas kernel
-            # (interpret mode executes the same kernel on CPU).
-            o = quant_decode_attention(
-                q[:, 0], ck.transpose(0, 2, 1, 3), ks.transpose(0, 2, 1),
-                cv.transpose(0, 2, 1, 3), vs.transpose(0, 2, 1), pos + 1,
-                block_s=128 if w % 128 == 0 else w,
-                interpret=kv_kernel == "interpret")[:, None]
-        else:
-            # XLA path: dequantize this layer's slice (transient); the TPU
-            # build fuses dequant HBM->VMEM via kernels.quant_decode.
-            kd = (ck.astype(jnp.float32) * ks[..., None]).astype(k.dtype)
-            vd = (cv.astype(jnp.float32) * vs[..., None]).astype(v.dtype)
-            o = attn_lib.attend_decode(q, kd, vd, pos=pos + 1,
-                                       ring=window is not None)
-        out = jnp.einsum("bshk,hkd->bsd", o, p[prefix + "wo"])
-        return h + out, ck, cv, (ks, vs)
     ck = write(ck, k.astype(ck.dtype))
     cv = write(cv, v.astype(cv.dtype))
     o = attn_lib.attend_decode(q, ck, cv, pos=pos + 1, ring=window is not None)
     out = jnp.einsum("bshk,hkd->bsd", o, p[prefix + "wo"])
     return h + out, ck, cv
+
+
+def _attn_decode_quant(cfg, p, h, pool, layer, pos, window, kv_kernel):
+    """One-token self-attention of layer ``layer`` against the whole int8
+    pool (module docstring). Returns h' and the pool with this token's
+    int8 K/V and scales written at ``[layer, row, :, slot]``.
+
+    ``kv_kernel``: "pallas" (fused HBM->VMEM dequant kernel), "interpret"
+    (the same kernel in Pallas interpret mode, CPU-safe) or "xla" (the
+    reference: dequantize this layer's slice; also ring windows)."""
+    from repro.kernels.quant_decode import quant_decode_attention, quantize_kv
+    b, w = pool["k"].shape[1], pool["k"].shape[3]
+    q, k, v, pos = _rope_qkv(cfg, p, h, pos)
+    slot = pos % w if window else jnp.minimum(pos, w - 1)
+    # One index per (row, head), so the update window is at most the
+    # minor Dh axis and the scatter keeps the pool in the kernel's layout.
+    rows = jnp.arange(b)[:, None]
+    heads = jnp.arange(k.shape[2])[None, :]
+    slot = jnp.broadcast_to(slot, (b,))[:, None]
+    k8, ks = quantize_kv(k[:, 0])                    # [B,KV,Dh], [B,KV]
+    v8, vs = quantize_kv(v[:, 0])
+    pool = {name: pool[name].at[layer, rows, heads, slot].set(
+                val, unique_indices=True)
+            for name, val in (("k", k8), ("v", v8), ("k_scale", ks),
+                              ("v_scale", vs))}
+    if kv_kernel != "xla" and window is None:
+        o = quant_decode_attention(
+            q[:, 0], pool["k"], pool["k_scale"], pool["v"], pool["v_scale"],
+            pos + 1, layer=layer, block_s=128 if w % 128 == 0 else w,
+            interpret=kv_kernel == "interpret")[:, None]
+    else:
+        def dequant(name):                           # -> [B,W,KV,Dh]
+            x = pool[name][layer].astype(jnp.float32) \
+                * pool[name + "_scale"][layer][..., None]
+            return jnp.swapaxes(x, 1, 2).astype(k.dtype)
+        # The barrier keeps the [B,W,KV,Dh] slices: folded into the
+        # attention dots, the swap leaves a bf16 dot contracting a
+        # non-minor axis, which XLA's CPU runtime cannot execute.
+        kd, vd = jax.lax.optimization_barrier((dequant("k"), dequant("v")))
+        o = attn_lib.attend_decode(q, kd, vd, pos=pos + 1,
+                                   ring=window is not None)
+    return h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), pool
 
 
 def _cross_decode_block(cfg, p, h, ck, cv, enc_len):
@@ -315,37 +364,28 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos, ctx: ModelCtx):
     window = ctx.window
     new = dict(cache)
 
-    if fam in ("dense", "vlm", "moe", "encdec"):
-        enc_len = cache["ck"].shape[2] if fam == "encdec" else 0
+    if fam in ("dense", "vlm", "moe", "encdec") and "k_scale" in cache:
+        h, new = _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx)
 
-        quant = "k_scale" in cache
+    elif fam in ("dense", "vlm", "moe", "encdec"):
+        enc_len = cache["ck"].shape[2] if fam == "encdec" else 0
 
         def body(carry, xs):
             xs = jax.lax.optimization_barrier(xs)   # see model._scan_layers
             lp = xs["p"]
-            if quant:
-                hh, ck, cv, (ks, vs) = _attn_decode_block(
-                    cfg, lp, carry, xs["k"], xs["v"], pos, window,
-                    scales=(xs["ks"], xs["vs"]), kv_kernel=ctx.kv_kernel)
-                ys = {"k": ck, "v": cv, "ks": ks, "vs": vs}
-            else:
-                hh, ck, cv = _attn_decode_block(cfg, lp, carry, xs["k"],
-                                                xs["v"], pos, window)
-                ys = {"k": ck, "v": cv}
+            hh, ck, cv = _attn_decode_block(cfg, lp, carry, xs["k"],
+                                            xs["v"], pos, window)
+            ys = {"k": ck, "v": cv}
             if fam == "encdec":
                 hh = _cross_decode_block(cfg, lp, hh, xs["ck"], xs["cv"], enc_len)
             hh = _mlp_block(cfg, lp, hh, ctx)
             return hh, ys
 
         xs = {"p": xp["layers"], "k": cache["k"], "v": cache["v"]}
-        if quant:
-            xs["ks"], xs["vs"] = cache["k_scale"], cache["v_scale"]
         if fam == "encdec":
             xs["ck"], xs["cv"] = cache["ck"], cache["cv"]
         h, ys = jax.lax.scan(body, h, xs)
         new["k"], new["v"] = ys["k"], ys["v"]
-        if quant:
-            new["k_scale"], new["v_scale"] = ys["ks"], ys["vs"]
 
     elif fam == "ssm":
         def body(carry, xs):
@@ -364,6 +404,35 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos, ctx: ModelCtx):
         raise ValueError(fam)
 
     return head_logits(cfg, yp, h), new
+
+
+def _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx):
+    """The layer loop over an int8 pool: the pool rides in the carry beside
+    h, and the loop runs over the layer index (and encdec's bf16 cross K/V).
+
+    The barrier (see model._scan_layers) holds the stacked weights with the
+    layer index, and the layer is indexed after it, so each dot reads its
+    layer's weights in place; a barrier on the indexed layer would copy
+    every layer's weights out of the stack once a tick."""
+    enc_len = cache["ck"].shape[2] if cfg.family == "encdec" else 0
+
+    def body(carry, xs):
+        hh, pool = carry
+        layers, layer = jax.lax.optimization_barrier((xp["layers"],
+                                                      xs["layer"]))
+        lp = jax.tree.map(lambda a: a[layer], layers)
+        hh, pool = _attn_decode_quant(cfg, lp, hh, pool, layer, pos,
+                                      window, ctx.kv_kernel)
+        if cfg.family == "encdec":
+            hh = _cross_decode_block(cfg, lp, hh, xs["ck"], xs["cv"], enc_len)
+        return (_mlp_block(cfg, lp, hh, ctx), pool), None
+
+    xs = {"layer": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
+    if cfg.family == "encdec":
+        xs["ck"], xs["cv"] = cache["ck"], cache["cv"]
+    pool = {n: cache[n] for n in ("k", "v", "k_scale", "v_scale")}
+    (h, pool), _ = jax.lax.scan(body, (h, pool), xs)
+    return h, {**cache, **pool}
 
 
 def _hybrid_decode(cfg, xp, h, cache, pos, window, ctx):

@@ -11,7 +11,8 @@ pool or ran alone (pinned in tests/test_serve_engine.py).
 
 ``kv_quant=True`` switches the pool to the int8 cache layout: prefill stays
 full-precision (a direct int8 cast would be garbage), the row is quantized
-per (token, head) on the way into the pool, and decode attends through
+per (token, head) and laid out heads-before-positions on the way into the
+pool (``models.decode`` module docstring), and decode attends through
 either the XLA reference dequant or the fused Pallas kernel
 (``kv_kernel="pallas"``; ``"interpret"`` runs the same kernel on CPU).
 """
@@ -28,6 +29,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.fed.serve import build_serve_fns
+from repro.models.decode import quantize_cache
 from repro.obs.telemetry import NULL
 
 QUANT_FAMILIES = ("dense", "vlm", "moe", "encdec")
@@ -119,7 +121,7 @@ class Engine:
         self._argmax = jax.jit(
             lambda lg: jnp.argmax(lg[:, 0, :], axis=-1).astype(jnp.int32))
         self._scatter = jax.jit(self._scatter_row)
-        self._quantize = jax.jit(self._quantize_row) if kv_quant else None
+        self._quantize = jax.jit(quantize_cache) if kv_quant else None
 
         # host-side slot state
         self._queue: Deque[Request] = deque()
@@ -152,15 +154,6 @@ class Engine:
         return jax.tree.map(
             lambda p, r: jax.lax.dynamic_update_slice_in_dim(
                 p, r.astype(p.dtype), slot, axis=1), pool, row)
-
-    def _quantize_row(self, row):
-        """Full-precision prefill cache -> int8 pool layout (k/v quantized
-        per (token, head); encdec cross ck/cv stay dense)."""
-        from repro.kernels.quant_decode import quantize_kv
-        out = dict(row)
-        out["k"], out["k_scale"] = quantize_kv(row["k"])
-        out["v"], out["v_scale"] = quantize_kv(row["v"])
-        return out
 
     # ------------------------------------------------------------ intake
 

@@ -11,6 +11,7 @@ from repro.kernels import ref
 from repro.kernels.quant_decode import quant_decode_attention, quantize_kv
 from repro.models import (ModelCtx, decode_step, init_cache, init_params,
                           model_specs, prefill)
+from repro.models.decode import quantize_cache
 
 
 @pytest.mark.parametrize("b,h,kv,s,d", [(1, 4, 4, 256, 64), (2, 8, 2, 512, 64)])
@@ -56,6 +57,31 @@ def test_kernel_matches_oracle_per_row_pos(pos):
                                       np.asarray(one))
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_stacked_pool_matches_layer_slice(layer):
+    """The kernel over the stacked pool [L,B,KV,S,Dh] at a traced layer
+    index equals the one-layer call on that layer's slice, bit for bit.
+    Rows: an empty slot (pos 1), one ending inside a tile, one at a tile's
+    end and a full one (pos = S)."""
+    n_layers, b, h, kv, s, d = 3, 4, 8, 2, 512, 64
+    key = jax.random.PRNGKey(7)
+    q = jax.random.normal(key, (b, h, d), jnp.bfloat16)
+    k8, ks = quantize_kv(jax.random.normal(jax.random.fold_in(key, 1),
+                                           (n_layers, b, kv, s, d)))
+    v8, vs = quantize_kv(jax.random.normal(jax.random.fold_in(key, 2),
+                                           (n_layers, b, kv, s, d)))
+    pos = jnp.asarray([1, 200, 384, s], jnp.int32)
+    stacked = jax.jit(lambda q, k8, ks, v8, vs, pos, layer:
+                      quant_decode_attention(q, k8, ks, v8, vs, pos,
+                                             layer=layer, block_s=128,
+                                             interpret=True))
+    got = stacked(q, k8, ks, v8, vs, pos, jnp.int32(layer))
+    want = quant_decode_attention(q, k8[layer], ks[layer], v8[layer],
+                                  vs[layer], pos, block_s=128,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_quantization_error_small():
     key = jax.random.PRNGKey(1)
     k = jax.random.normal(key, (2, 2, 128, 64), jnp.float32)
@@ -76,10 +102,8 @@ def test_quantized_decode_close_to_bf16():
     outs = {}
     for quant in (False, True):
         cache = cache0
-        if quant:   # quantize the prefilled bf16 cache (prod: prefill writes q8)
-            ck8, cks = quantize_kv(cache0["k"])
-            cv8, cvs = quantize_kv(cache0["v"])
-            cache = {"k": ck8, "v": cv8, "k_scale": cks, "v_scale": cvs}
+        if quant:   # the prefilled cache in the int8 pool's layout, as
+            cache = quantize_cache(cache0)          # the serve engine makes it
         lg, _ = decode_step(cfg, params, cache, tokens[:, S - 1:],
                             jnp.int32(S - 1), ModelCtx(kind="decode"))
         outs[quant] = np.asarray(lg, np.float32)

@@ -8,13 +8,19 @@ The topology is described inside a fixture (only the worker that runs this
 file loads the TPU library), and the persistent compile cache is off around
 the compiles, since entries compiled for an absent chip cannot be read back.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_arch
+from repro.configs.base import ShapeConfig
+from repro.fed.serve import build_serve_fns
 from repro.kernels.quant_decode import quant_decode_attention
 from repro.kernels.quantize import dequantize, quantize_stoch
 from repro.kernels.storm_update import adafbio_update, storm_update
@@ -87,3 +93,75 @@ def test_quant_decode_compiles(one_chip, b, h, kv, s, dh):
              ((b, h, dh), jnp.bfloat16), ((b, kv, s, dh), jnp.int8),
              ((b, kv, s), jnp.float32), ((b, kv, s, dh), jnp.int8),
              ((b, kv, s), jnp.float32), ((b,), jnp.int32))
+
+
+# An instruction with an array result: name, dtype, dims, layout, opcode.
+INSTR = re.compile(r"%\S+ = (\w+)\[([\d,]*)\](\{[^}]*\})? ([\w-]+)\(")
+
+
+def _hbm_values(text):
+    """(dtype, element count, opcode, line) of every instruction the
+    program runs whose result is one array in HBM. Instructions inside a
+    fusion are left out (they make no buffer of their own). A result laid
+    out in another memory space (``S(n)`` in its layout) is the compiler
+    staging a buffer in VMEM; so are async copies (``copy-start``/
+    ``copy-done``), which move a staged buffer back. Where the whole pool
+    fits in VMEM (the reduced size), the compiler stages it there in every
+    layer."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=(%[\w.\-]+)", text))
+    inside = False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", line)
+        if head:
+            inside = head.group(1) in fused
+        m = INSTR.search(line)
+        if m and not inside and "S(" not in (m.group(3) or ""):
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            yield m.group(1), math.prod(dims), m.group(4), line
+
+
+def _moves_axes(line):
+    perm = re.search(r"dimensions=\{([\d,]*)\}", line).group(1)
+    perm = [int(d) for d in perm.split(",") if d]
+    return perm != sorted(perm)
+
+
+@pytest.mark.parametrize("n_layers,slots,max_len", [
+    (2, 8, 512),                 # reduced
+    (4, 32, 4096),               # the DeepSeek-67B serving cell's stage
+])
+def test_int8_decode_program_keeps_pool_in_place(one_chip, n_layers, slots,
+                                                  max_len):
+    """The serve engine's int8 decode program (Pallas kernel) for the
+    DeepSeek-67B stage at published widths (KV 8, head_dim 128). The pool
+    rides through the layer loop in the kernel's layout: no transpose
+    moves an axis, no s8 value the size of one layer's pool slice is made
+    in HBM, and each s8 pool leaf (k, v) is copied whole at most once (the
+    decode does not donate its pool, so the loop's carry starts as a
+    copy). The dots read each layer's MLP weights in place: no bf16 value
+    the size of one layer's [d_model, d_ff] weight is made in HBM."""
+    cfg = dataclasses.replace(get_arch("deepseek-67b"), n_layers=n_layers,
+                              head_dim=128)
+    fns = build_serve_fns(cfg, ShapeConfig("serve_decode", max_len, slots,
+                                           "decode"),
+                          None, kv_quant=True, kv_kernel="pallas")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+    text = fns["decode"].lower(
+        on_chip(fns["params_abs"]), on_chip(fns["cache_abs"]),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    pool = math.prod(fns["cache_abs"]["k"].shape)
+    values = list(_hbm_values(text))
+    assert [line for _, _, op, line in values
+            if op == "transpose" and _moves_axes(line)] == []
+    assert [op for dt, n, op, _ in values
+            if dt == "s8" and n == pool // n_layers] == []
+    assert len([op for dt, n, op, _ in values if dt == "s8" and n == pool
+                and op == "copy"]) <= 2
+    assert [op for dt, n, op, _ in values
+            if dt == "bf16" and n == cfg.d_model * cfg.d_ff] == []
