@@ -19,6 +19,15 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # if >0, a shared (always-on) dense ffn of this width runs alongside experts
     d_ff_shared: int = 0
+    # expert parallelism (hybrid_moe): the router scores ``n_routed`` experts
+    # (0 = n_experts) and this chip holds ``n_experts`` of them, ids
+    # first_expert .. first_expert + n_experts - 1
+    n_routed: int = 0
+    first_expert: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,7 +50,7 @@ class EncoderConfig:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                # dense | moe | ssm | hybrid | hybrid_moe | encdec | vlm
     source: str                # citation from the assignment
     n_layers: int
     d_model: int
@@ -58,6 +67,17 @@ class ArchConfig:
     encoder: Optional[EncoderConfig] = None
     # hybrid: apply the shared attention block every `shared_attn_every` layers
     shared_attn_every: int = 0
+    # hybrid_moe: each layer's mixer, "mamba" or "attention", in order; every
+    # layer has its own weights and an MoE block
+    layer_types: Tuple[str, ...] = ()
+    # granite-style multipliers: embeddings scaled up, residual branches
+    # scaled down, logits divided; attention_multiplier 0 -> head_dim**-0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
+    use_rope: bool = True      # False: no positional encoding (NoPE)
+    tie_embeddings: bool = False
     # sliding-window attention width used for the long_500k serve variant; dense
     # archs fall back to this window there (see DESIGN.md §5).
     long_context_window: int = 4096
@@ -80,12 +100,20 @@ class ArchConfig:
     def attn_free(self) -> bool:
         return self.family == "ssm"
 
+    @property
+    def attn_scale(self) -> float:
+        return self.attention_multiplier or self.resolved_head_dim ** -0.5
+
+    def n_of(self, kind: str) -> int:
+        """Layers of the pattern whose mixer is ``kind``."""
+        return sum(t == kind for t in self.layer_types)
+
     def param_count(self) -> int:
         """Approximate parameter count (for roofline MODEL_FLOPS)."""
         d = self.d_model
         n = 0
         n += self.vocab * d            # embed
-        n += self.vocab * d + d        # head (untied) + final norm
+        n += d if self.tie_embeddings else self.vocab * d + d  # head + norm
         attn = mlp = ssm = 0
         if self.n_heads:
             hd = self.resolved_head_dim
@@ -95,7 +123,7 @@ class ArchConfig:
                 attn += (self.n_heads + 2 * self.n_kv_heads) * hd
         if self.moe is not None:
             e = self.moe
-            mlp = d * e.n_experts + e.n_experts * 3 * d * e.d_ff_expert
+            mlp = d * e.router_width + e.n_experts * 3 * d * e.d_ff_expert
             if e.d_ff_shared:
                 mlp += 3 * d * e.d_ff_shared
         elif self.d_ff:
@@ -106,7 +134,10 @@ class ArchConfig:
             # in_proj (x,z), conv, dt/B/C projections, out_proj (approx)
             ssm = (2 * d * di + s.conv_width * di
                    + di * s.state_dim * 2 + di + di * d)
-        if self.shared_attn_every:
+        if self.family == "hybrid_moe":
+            n += (ssm * self.n_of("mamba") + attn * self.n_of("attention")
+                  + mlp * self.n_layers)
+        elif self.shared_attn_every:
             # hybrid (zamba2-style): SSM per layer + ONE weight-tied attn+mlp block
             n += ssm * self.n_layers + attn + mlp
         elif self.family == "ssm":
@@ -378,6 +409,7 @@ _ARCH_IDS = [
     "granite-20b",
     "llama4-scout-17b-a16e",
     "qwen1.5-4b",
+    "granite-4.0-h-small",
 ]
 
 
@@ -421,6 +453,7 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     if cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2),
+            n_routed=min(cfg.moe.n_routed, 8),
             d_ff_expert=min(cfg.moe.d_ff_expert, 128),
             d_ff_shared=min(cfg.moe.d_ff_shared, 128) if cfg.moe.d_ff_shared else 0)
     if cfg.ssm is not None:
@@ -431,6 +464,8 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
         changes["encoder"] = EncoderConfig(n_layers=2)
     if cfg.shared_attn_every:
         changes["shared_attn_every"] = 2
+    if cfg.layer_types:
+        changes["layer_types"] = ("mamba", "attention")
     if cfg.n_prefix_embeds:
         changes["n_prefix_embeds"] = 8
     changes.update(overrides)

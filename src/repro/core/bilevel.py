@@ -133,6 +133,20 @@ def lm_bilevel_problem(cfg, ctx, nu: float,
     def f(xp, yp, batch):
         return f_from_feats(yp, feats_fn(xp, batch), batch)
 
+    if cfg.tie_embeddings:
+        # the head is x's embedding table, so the LL loss reaches x through
+        # more than the features: no factored path
+        def _xent_tied(xp, yp, batch):
+            logits = head_logits(cfg, yp, feats_fn(xp, batch)[:, :-1],
+                                 xp["embed"])
+            return softmax_xent(logits, batch["tokens"][:, 1:])
+
+        def g(xp, yp, batch):
+            return _xent_tied(xp, yp, batch) + 0.5 * nu * tree_sqnorm(yp)
+
+        def f(xp, yp, batch):
+            return _xent_tied(xp, yp, batch)
+
     def _nc(batch):
         n = batch["tokens"].shape[0]
         if microbatch is None or n <= microbatch:
@@ -169,8 +183,10 @@ def lm_bilevel_problem(cfg, ctx, nu: float,
         c = _constrain_like(_axes["y"])
         return microbatched_grad(g, 1, _nc(batch), c, acc_dtype)(xp, yp, batch)
 
-    return BilevelProblem(f=f, g=g, features=feats_fn,
-                          f_from_feats=f_from_feats, g_from_feats=g_from_feats,
+    factored = {} if cfg.tie_embeddings else dict(
+        features=feats_fn, f_from_feats=f_from_feats,
+        g_from_feats=g_from_feats)
+    return BilevelProblem(f=f, g=g, **factored,
                           grad_f_xy=grad_f_xy, grad_g_y=grad_g_y,
                           constrain_x=_constrain_like(_axes["x"]),
                           constrain_y=_constrain_like(_axes["y"]))

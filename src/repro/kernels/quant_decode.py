@@ -37,7 +37,7 @@ def quantize_kv(k: jax.Array):
 
 
 def _kernel(pos_ref, layer_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, bs: int, dh: int):
+            m_scr, l_scr, acc_scr, *, bs: int, scale: float):
     # blocks: q [G,D]; k/v [bs,D] int8; ks/vs [KV,bs] (every head); o [G,D]
     bi, ki, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     pos = pos_ref[bi]
@@ -50,7 +50,7 @@ def _kernel(pos_ref, layer_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 
     @pl.when(si * bs < pos)            # blocks wholly past pos hold no keys
     def _block():
-        qv = q_ref[...].astype(jnp.float32) * dh ** -0.5        # [G, D]
+        qv = q_ref[...].astype(jnp.float32) * scale             # [G, D]
         # Per-token scales multiply the logits and the probabilities rather
         # than the int8 rows, so they stay a lane vector [1, bs].
         s = jax.lax.dot_general(qv, k_ref[...].astype(jnp.float32),
@@ -78,12 +78,13 @@ def _kernel(pos_ref, layer_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 def quant_decode_attention(q: jax.Array, k8: jax.Array, k_scale: jax.Array,
                            v8: jax.Array, v_scale: jax.Array, pos, *,
                            layer=None, block_s: int = 512,
-                           interpret: bool = False):
+                           scale=None, interpret: bool = False):
     """q: [B,H,Dh] (one token); k8/v8: [B,KV,S,Dh] int8 with scales
     [B,KV,S] f32, or the stacked pool [L,B,KV,S,Dh] with scales [L,B,KV,S]
     and ``layer`` (an int32 scalar, may be traced) naming the layer to
     attend over; pos: valid length — scalar or [B] per-row vector
-    (continuous batching). Returns [B,H,Dh]."""
+    (continuous batching); ``scale`` multiplies the logits (default
+    Dh**-0.5). Returns [B,H,Dh]."""
     if k8.ndim == 4:          # one layer: a unit layer axis costs no copy
         k8, k_scale, v8, v_scale = k8[None], k_scale[None], v8[None], \
             v_scale[None]
@@ -114,7 +115,8 @@ def quant_decode_attention(q: jax.Array, k8: jax.Array, k_scale: jax.Array,
     def head_block(bi, ki, si, pos_ref, layer_ref):
         return bi, ki, 0, 0
 
-    kernel = functools.partial(_kernel, bs=bs, dh=dh)
+    kernel = functools.partial(_kernel, bs=bs,
+                               scale=dh ** -0.5 if scale is None else scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

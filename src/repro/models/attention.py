@@ -55,13 +55,14 @@ def _mask(sq: int, sk: int, causal: bool, window: Optional[int],
 
 def attend_full(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 causal: bool = True, window: Optional[int] = None,
-                q_offset: int = 0) -> jax.Array:
-    """Plain softmax attention. q: [B,Sq,H,Dh]; k,v: [B,Sk,KV,Dh]."""
+                q_offset: int = 0, scale: Optional[float] = None) -> jax.Array:
+    """Plain softmax attention. q: [B,Sq,H,Dh]; k,v: [B,Sk,KV,Dh].
+    ``scale`` multiplies the logits (default Dh**-0.5)."""
     b, sq, h, dh = q.shape
     sk, kv = k.shape[1], k.shape[2]
     # operands stay in input dtype (bf16 -> MXU); accumulation is f32 via
     # preferred_element_type, so no f32 copies of K/V are materialized.
-    q5 = _grouped(q, kv) * jnp.asarray(dh ** -0.5, q.dtype)
+    q5 = _grouped(q, kv) * jnp.asarray(scale or dh ** -0.5, q.dtype)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q5, k,
                         preferred_element_type=jnp.float32)
     m = _mask(sq, sk, causal, window, q_offset)
@@ -74,7 +75,7 @@ def attend_full(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def attend_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  causal: bool = True, window: Optional[int] = None,
-                 chunk: int = 1024) -> jax.Array:
+                 chunk: int = 1024, scale: Optional[float] = None) -> jax.Array:
     """Chunked (flash-style) attention over KV blocks: O(Sq*chunk) live scores.
 
     Forward-only usage (prefill); the train path uses attend_full under
@@ -83,11 +84,11 @@ def attend_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, sq, h, dh = q.shape
     sk, kv = k.shape[1], k.shape[2]
     if sk <= chunk:
-        return attend_full(q, k, v, causal=causal, window=window)
+        return attend_full(q, k, v, causal=causal, window=window, scale=scale)
     assert sk % chunk == 0, (sk, chunk)
     nkv = sk // chunk
     g = h // kv
-    q5 = _grouped(q, kv) * jnp.asarray(dh ** -0.5, q.dtype)
+    q5 = _grouped(q, kv) * jnp.asarray(scale or dh ** -0.5, q.dtype)
     qpos = jnp.arange(sq)
 
     kc = k.reshape(b, nkv, chunk, kv, dh).transpose(1, 0, 2, 3, 4)
@@ -125,7 +126,8 @@ def attend_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, *,
-                  pos: jax.Array, ring: bool = False) -> jax.Array:
+                  pos: jax.Array, ring: bool = False,
+                  scale: Optional[float] = None) -> jax.Array:
     """One-token attention vs a cache.
 
     q: [B,1,H,Dh]; k_cache/v_cache: [B,Smax,KV,Dh]; pos: count of valid tokens
@@ -136,7 +138,7 @@ def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, *,
     """
     b, smax, kv, dh = k_cache.shape
     h = q.shape[2]
-    q5 = _grouped(q, kv) * jnp.asarray(dh ** -0.5, q.dtype)
+    q5 = _grouped(q, kv) * jnp.asarray(scale or dh ** -0.5, q.dtype)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q5.astype(k_cache.dtype), k_cache,
                         preferred_element_type=jnp.float32)
     slots = jnp.arange(smax)

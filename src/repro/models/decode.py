@@ -5,6 +5,9 @@ Cache layouts (leading dim scans over layers):
   int8 (quant)  : {"k","v": [L,B,KV,W,Dh] int8, "k_scale","v_scale": [L,B,KV,W] f32}
   ssm           : {"h": [L,B,di,N] f32, "conv": [L,B,cw-1,di]}
   hybrid        : mamba2 state + shared-attn KV [nseg,B,W,KV,Dh]
+  hybrid_moe    : mamba2 state {"h": [Lm,B,H,P,N] f32, "conv": [Lm,B,cw-1,C]}
+                  of its Lm mamba layers beside the (int8) K/V of its La
+                  attention layers, in one pool
   encdec        : self KV [L,...] + cross KV [L,B,Senc,KV,Dh] (built at prefill)
 
 Batch is axis 1 of every leaf, so the serve engine scatters a prefilled row
@@ -33,7 +36,8 @@ from repro.configs.base import ArchConfig
 from repro.models import attention as attn_lib
 from repro.models import ssm as ssm_lib
 from repro.models.model import (ModelCtx, _mlp_block, embed_tokens,
-                                encoder_forward, head_logits, rmsnorm)
+                                encoder_forward, head_logits, layer_runs,
+                                moe_block, residual, rmsnorm)
 
 
 # ------------------------------------------------------------------ cache init
@@ -50,6 +54,9 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int,
     ``kernels.quant_decode`` on TPU (the XLA reference path dequantizes one
     layer slice at a time inside the layer loop)."""
     L = cfg.n_layers
+    Lm = L
+    if cfg.family == "hybrid_moe":
+        L, Lm = cfg.n_of("attention"), cfg.n_of("mamba")
     hd = cfg.resolved_head_dim if cfg.n_heads else 0
     w = min(window or max_len, max_len)
     spec: Dict[str, Any] = {}
@@ -60,7 +67,7 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int,
         return jax.ShapeDtypeStruct(shape, dt)
 
     fam = cfg.family
-    if fam in ("dense", "vlm", "moe", "encdec"):
+    if fam in ("dense", "vlm", "moe", "encdec", "hybrid_moe"):
         if quant:
             q_ax = ("layers", "batch", "kv_heads", "cache_seq", "head_dim")
             kvs = (L, batch, cfg.n_kv_heads, w, hd)
@@ -77,19 +84,20 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int,
         ckvs = (L, batch, enc_len, cfg.n_kv_heads, hd)
         spec["ck"], spec["cv"] = sds(ckvs), sds(ckvs)
         axes["ck"] = axes["cv"] = kv_ax
-    if fam in ("ssm", "hybrid"):
+    if fam in ("ssm", "hybrid", "hybrid_moe"):
         s = cfg.ssm
         di = s.expand * cfg.d_model
         if s.version == 1:
-            spec["h"] = sds((L, batch, di, s.state_dim), jnp.float32)
+            spec["h"] = sds((Lm, batch, di, s.state_dim), jnp.float32)
             axes["h"] = ("layers", "batch", "ssm_inner", "ssm_state")
             conv_ch = di
         else:
             nh = di // s.head_dim
-            spec["h"] = sds((L, batch, nh, s.head_dim, s.state_dim), jnp.float32)
+            spec["h"] = sds((Lm, batch, nh, s.head_dim, s.state_dim),
+                            jnp.float32)
             axes["h"] = ("layers", "batch", "ssm_inner", None, "ssm_state")
             conv_ch = di + 2 * s.state_dim
-        spec["conv"] = sds((L, batch, s.conv_width - 1, conv_ch))
+        spec["conv"] = sds((Lm, batch, s.conv_width - 1, conv_ch))
         axes["conv"] = ("layers", "batch", None, "ssm_inner")
     if fam == "hybrid":
         nseg = cfg.n_layers // cfg.shared_attn_every
@@ -134,9 +142,11 @@ def _rope_qkv(cfg, p, h, pos, prefix=""):
     hn = rmsnorm(h, p[prefix + "ln_attn"], cfg.norm_eps)
     q, k, v = _qkv(cfg, p, hn, prefix)
     pos = jnp.asarray(pos, jnp.int32)
-    posv = pos[:, None] if pos.ndim == 1 else jnp.full((1, 1), pos, jnp.int32)
-    q = attn_lib.rope(q, posv, cfg.rope_theta)
-    k = attn_lib.rope(k, posv, cfg.rope_theta)
+    if cfg.use_rope:
+        posv = pos[:, None] if pos.ndim == 1 else \
+            jnp.full((1, 1), pos, jnp.int32)
+        q = attn_lib.rope(q, posv, cfg.rope_theta)
+        k = attn_lib.rope(k, posv, cfg.rope_theta)
     return q, k, v, pos
 
 
@@ -158,15 +168,17 @@ def _attn_decode_block(cfg, p, h, ck, cv, pos, window, prefix=""):
 
     ck = write(ck, k.astype(ck.dtype))
     cv = write(cv, v.astype(cv.dtype))
-    o = attn_lib.attend_decode(q, ck, cv, pos=pos + 1, ring=window is not None)
+    o = attn_lib.attend_decode(q, ck, cv, pos=pos + 1, ring=window is not None,
+                               scale=cfg.attn_scale)
     out = jnp.einsum("bshk,hkd->bsd", o, p[prefix + "wo"])
-    return h + out, ck, cv
+    return residual(cfg, h, out), ck, cv
 
 
 def _attn_decode_quant(cfg, p, h, pool, layer, pos, window, kv_kernel):
     """One-token self-attention of layer ``layer`` against the whole int8
-    pool (module docstring). Returns h' and the pool with this token's
-    int8 K/V and scales written at ``[layer, row, :, slot]``.
+    pool (module docstring). Returns the attention's output (before the
+    residual) and the pool with this token's int8 K/V and scales written at
+    ``[layer, row, :, slot]``.
 
     ``kv_kernel``: "pallas" (fused HBM->VMEM dequant kernel), "interpret"
     (the same kernel in Pallas interpret mode, CPU-safe) or "xla" (the
@@ -190,7 +202,7 @@ def _attn_decode_quant(cfg, p, h, pool, layer, pos, window, kv_kernel):
         o = quant_decode_attention(
             q[:, 0], pool["k"], pool["k_scale"], pool["v"], pool["v_scale"],
             pos + 1, layer=layer, block_s=128 if w % 128 == 0 else w,
-            interpret=kv_kernel == "interpret")[:, None]
+            scale=cfg.attn_scale, interpret=kv_kernel == "interpret")[:, None]
     else:
         def dequant(name):                           # -> [B,W,KV,Dh]
             x = pool[name][layer].astype(jnp.float32) \
@@ -201,8 +213,9 @@ def _attn_decode_quant(cfg, p, h, pool, layer, pos, window, kv_kernel):
         # non-minor axis, which XLA's CPU runtime cannot execute.
         kd, vd = jax.lax.optimization_barrier((dequant("k"), dequant("v")))
         o = attn_lib.attend_decode(q, kd, vd, pos=pos + 1,
-                                   ring=window is not None)
-    return h + jnp.einsum("bshk,hkd->bsd", o, p["wo"]), pool
+                                   ring=window is not None,
+                                   scale=cfg.attn_scale)
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), pool
 
 
 def _cross_decode_block(cfg, p, h, ck, cv, enc_len):
@@ -294,11 +307,62 @@ def prefill(cfg: ArchConfig, params, batch, cache, ctx: ModelCtx):
 
     elif fam == "hybrid":
         h, cache = _hybrid_prefill(cfg, xp, h, cache, ctx, pos, w, window)
+    elif fam == "hybrid_moe":
+        h, cache = _pattern_prefill(cfg, xp, h, cache, ctx, pos, w, window)
     else:
         raise ValueError(fam)
 
-    logits = head_logits(cfg, yp, h[:, -1:])
+    logits = head_logits(cfg, yp, h[:, -1:], xp["embed"])
     return logits, cache
+
+
+def _pattern_prefill(cfg, xp, h, cache, ctx, pos, w, window):
+    """hybrid_moe's prompt: one scan per run of the pattern, each with the
+    barrier on the stacked weights and the layer indexed after it (see
+    ``_quant_decode_layers``); returns h and the mamba states and the
+    attention K/V of the layers, stacked per kind."""
+    out = {"h": [], "conv": [], "k": [], "v": []}
+    for kind, lo, n, j0 in layer_runs(cfg):
+        def body(carry, i, kind=kind, lo=lo, j0=j0):
+            (mix, ffn), i = jax.lax.optimization_barrier(
+                ((xp[kind], xp["layers"]), i))
+            mp = jax.tree.map(lambda a: a[j0 + i], mix)
+            if kind == "mamba":
+                with jax.named_scope("prefill/mamba"):
+                    hn = rmsnorm(carry, mp["ln"], cfg.norm_eps)
+                    y, (hst, conv) = ssm_lib.mamba2_seq(
+                        cfg, mp, hn, chunk=ctx.ssm_chunk)
+                    ys = {"h": hst, "conv": conv.astype(cache["conv"].dtype)}
+            else:
+                with jax.named_scope("prefill/attn"):
+                    hn = rmsnorm(carry, mp["ln_attn"], cfg.norm_eps)
+                    q, k, v = _qkv(cfg, mp, hn)
+                    if cfg.use_rope:
+                        q = attn_lib.rope(q, pos, cfg.rope_theta)
+                        k = attn_lib.rope(k, pos, cfg.rope_theta)
+                    if k.shape[1] > ctx.attn_chunk:
+                        o = attn_lib.attend_flash(
+                            q, k, v, causal=True, window=window,
+                            chunk=ctx.attn_chunk, scale=cfg.attn_scale)
+                    else:
+                        o = attn_lib.attend_full(q, k, v, causal=True,
+                                                 window=window,
+                                                 scale=cfg.attn_scale)
+                    y = jnp.einsum("bshk,hkd->bsd", o, mp["wo"])
+                    ys = {"k": _fill_ring(k, w, window).astype(
+                              cache["k"].dtype),
+                          "v": _fill_ring(v, w, window).astype(
+                              cache["v"].dtype)}
+            carry = residual(cfg, carry, y)
+            with jax.named_scope("prefill/moe"):
+                carry, _ = moe_block(cfg, ffn, carry, layer=lo + i)
+            return carry, ys
+        h, ys = jax.lax.scan(body, h, jnp.arange(n, dtype=jnp.int32))
+        for name, a in ys.items():
+            out[name].append(a)
+    new = dict(cache)
+    new.update({name: jnp.concatenate(a, 0) for name, a in out.items()})
+    return h, new
 
 
 def _hybrid_prefill(cfg, xp, h, cache, ctx, pos, w, window):
@@ -359,12 +423,15 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos, ctx: ModelCtx):
     at the same position) or [B] per-row (continuous batching).
     Returns (logits [B,1,V], new cache)."""
     xp, yp = params["x"], params["y"]
-    h = jnp.take(xp["embed"], token, axis=0)
+    h = embed_tokens(cfg, xp, token, None)
     fam = cfg.family
     window = ctx.window
     new = dict(cache)
 
-    if fam in ("dense", "vlm", "moe", "encdec") and "k_scale" in cache:
+    if fam == "hybrid_moe":
+        h, new = _pattern_decode_layers(cfg, xp, h, cache, pos, window, ctx)
+
+    elif fam in ("dense", "vlm", "moe", "encdec") and "k_scale" in cache:
         h, new = _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx)
 
     elif fam in ("dense", "vlm", "moe", "encdec"):
@@ -403,7 +470,7 @@ def decode_step(cfg: ArchConfig, params, cache, token, pos, ctx: ModelCtx):
     else:
         raise ValueError(fam)
 
-    return head_logits(cfg, yp, h), new
+    return head_logits(cfg, yp, h, xp["embed"]), new
 
 
 def _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx):
@@ -421,8 +488,9 @@ def _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx):
         layers, layer = jax.lax.optimization_barrier((xp["layers"],
                                                       xs["layer"]))
         lp = jax.tree.map(lambda a: a[layer], layers)
-        hh, pool = _attn_decode_quant(cfg, lp, hh, pool, layer, pos,
-                                      window, ctx.kv_kernel)
+        out, pool = _attn_decode_quant(cfg, lp, hh, pool, layer, pos,
+                                       window, ctx.kv_kernel)
+        hh = hh + out
         if cfg.family == "encdec":
             hh = _cross_decode_block(cfg, lp, hh, xs["ck"], xs["cv"], enc_len)
         return (_mlp_block(cfg, lp, hh, ctx), pool), None
@@ -433,6 +501,57 @@ def _quant_decode_layers(cfg, xp, h, cache, pos, window, ctx):
     pool = {n: cache[n] for n in ("k", "v", "k_scale", "v_scale")}
     (h, pool), _ = jax.lax.scan(body, (h, pool), xs)
     return h, {**cache, **pool}
+
+
+def _pattern_decode_layers(cfg, xp, h, cache, pos, window, ctx):
+    """hybrid_moe's decode tick: one scan per run of the pattern, with the
+    whole pool (the mamba state and conv state, and the attention K/V,
+    int8 or not) riding in the carry beside h, as in
+    ``_quant_decode_layers``: a mamba layer updates its state in place at
+    its index, an attention layer writes its token's K/V at its index.
+    The returned pool also holds ``expert_tokens`` [L, n_experts]: the
+    tokens routed to each held expert of each layer in this tick, over
+    every row of the batch (an empty slot's row too)."""
+    quant = "k_scale" in cache
+    names = ("h", "conv", "k", "v") + (("k_scale", "v_scale") if quant
+                                       else ())
+    pool = {n: cache[n] for n in names}
+    routed = []
+    for kind, lo, n, j0 in layer_runs(cfg):
+        def body(carry, i, kind=kind, lo=lo, j0=j0):
+            hh, pool = carry
+            (mix, ffn), i = jax.lax.optimization_barrier(
+                ((xp[kind], xp["layers"]), i))
+            j = j0 + i
+            mp = jax.tree.map(lambda a: a[j], mix)
+            if kind == "mamba":
+                with jax.named_scope("decode/mamba"):
+                    hn = rmsnorm(hh, mp["ln"], cfg.norm_eps)
+                    y, (hst, conv) = ssm_lib.mamba2_decode(
+                        cfg, mp, hn, pool["h"][j], pool["conv"][j])
+                    pool = {**pool, "h": pool["h"].at[j].set(hst),
+                            "conv": pool["conv"].at[j].set(
+                                conv.astype(pool["conv"].dtype))}
+                    hh = residual(cfg, hh, y)
+            elif quant:
+                with jax.named_scope("decode/attn"):
+                    y, kv = _attn_decode_quant(cfg, mp, hh, pool, j, pos,
+                                               window, ctx.kv_kernel)
+                    pool = {**pool, **kv}
+                    hh = residual(cfg, hh, y)
+            else:
+                with jax.named_scope("decode/attn"):
+                    hh, ck, cv = _attn_decode_block(
+                        cfg, mp, hh, pool["k"][j], pool["v"][j], pos, window)
+                    pool = {**pool, "k": pool["k"].at[j].set(ck),
+                            "v": pool["v"].at[j].set(cv)}
+            with jax.named_scope("decode/moe"):
+                hh, sizes = moe_block(cfg, ffn, hh, layer=lo + i)
+            return (hh, pool), sizes
+        (h, pool), sizes = jax.lax.scan(body, (h, pool),
+                                        jnp.arange(n, dtype=jnp.int32))
+        routed.append(sizes)
+    return h, {**cache, **pool, "expert_tokens": jnp.concatenate(routed, 0)}
 
 
 def _hybrid_decode(cfg, xp, h, cache, pos, window, ctx):

@@ -10,6 +10,8 @@ size is O(1) in depth. Families:
   moe        : GQA attention + top-k MoE (optional shared FFN)
   ssm        : mamba1 mixer only
   hybrid     : mamba2 mixers + ONE weight-tied shared attention block every k layers
+  hybrid_moe : a published pattern of mamba2 and GQA layers (``layer_types``),
+               each with its own weights and an MoE block of held experts
   encdec     : whisper-style encoder (stubbed frontend embeds) + cross-attn decoder
 """
 from __future__ import annotations
@@ -95,6 +97,12 @@ def model_specs(cfg: ArchConfig) -> Dict[str, Any]:
     elif fam == "hybrid":
         layers = ssm_lib.mamba2_specs(cfg, L)
         x["shared"] = {**_attn_specs(cfg, 0), **_mlp_specs(cfg, 0, cfg.d_ff)}
+    elif fam == "hybrid_moe":
+        layers = {**moe_lib.moe_specs(cfg, L),
+                  "ln_mlp": ParamSpec((L, d), ("layers", "embed"),
+                                      init="ones", dtype="float32")}
+        x["mamba"] = ssm_lib.mamba2_specs(cfg, cfg.n_of("mamba"))
+        x["attn"] = _attn_specs(cfg, cfg.n_of("attention"))
     elif fam == "encdec":
         layers = {**_attn_specs(cfg, L), **_mlp_specs(cfg, L, cfg.d_ff)}
         layers.update(_attn_specs(cfg, L, prefix="c"))          # cross-attention
@@ -106,10 +114,10 @@ def model_specs(cfg: ArchConfig) -> Dict[str, Any]:
     else:
         raise ValueError(fam)
     x["layers"] = layers
-    y = {
-        "final_norm": ParamSpec((d,), ("embed",), init="ones", dtype="float32"),
-        "head": ParamSpec((d, cfg.vocab), ("embed", "vocab")),
-    }
+    y = {"final_norm": ParamSpec((d,), ("embed",), init="ones",
+                                 dtype="float32")}
+    if not cfg.tie_embeddings:           # tied: the head is x["embed"]
+        y["head"] = ParamSpec((d, cfg.vocab), ("embed", "vocab"))
     return {"x": x, "y": y}
 
 
@@ -124,6 +132,13 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
     return x * (r.astype(x.dtype) * w.astype(x.dtype))
 
 
+def residual(cfg: ArchConfig, h: jax.Array, y: jax.Array) -> jax.Array:
+    """h + y, the branch scaled by the residual multiplier where it has one."""
+    if cfg.residual_multiplier == 1.0:
+        return h + y
+    return h + jnp.asarray(cfg.residual_multiplier, y.dtype) * y
+
+
 def _attn_block(cfg: ArchConfig, p, h, ctx: ModelCtx, *, pos, causal=True,
                 prefix="", kv_h=None, kv_pos=None):
     """Self- or cross-attention block. h: [B,S,d]. kv_h: source for K/V (cross)."""
@@ -136,7 +151,7 @@ def _attn_block(cfg: ArchConfig, p, h, ctx: ModelCtx, *, pos, causal=True,
         q = q + p[prefix + "bq"]
         k = k + p[prefix + "bk"]
         v = v + p[prefix + "bv"]
-    if kv_h is None:                                      # RoPE for self-attn only
+    if kv_h is None and cfg.use_rope:                     # RoPE for self-attn only
         q = attn_lib.rope(q, pos, cfg.rope_theta)
         kp = pos if kv_pos is None else kv_pos
         k = attn_lib.rope(k, kp, cfg.rope_theta)
@@ -146,13 +161,15 @@ def _attn_block(cfg: ArchConfig, p, h, ctx: ModelCtx, *, pos, causal=True,
         # chunked flash path: bounds live scores to O(Sq*chunk) in fwd AND bwd
         # (checkpointed chunk body), for train, prefill and cross-attention.
         o = attn_lib.attend_flash(q, k, v, causal=causal and kv_h is None,
-                                  window=ctx.window, chunk=ctx.attn_chunk)
+                                  window=ctx.window, chunk=ctx.attn_chunk,
+                                  scale=cfg.attn_scale)
     elif kv_h is not None:
-        o = attn_lib.attend_full(q, k, v, causal=False)
+        o = attn_lib.attend_full(q, k, v, causal=False, scale=cfg.attn_scale)
     else:
-        o = attn_lib.attend_full(q, k, v, causal=causal, window=ctx.window)
+        o = attn_lib.attend_full(q, k, v, causal=causal, window=ctx.window,
+                                 scale=cfg.attn_scale)
     out = jnp.einsum("bshk,hkd->bsd", o, p[prefix + "wo"])
-    return h + out, (k, v)
+    return residual(cfg, h, out), (k, v)
 
 
 def _mlp_block(cfg: ArchConfig, p, h, ctx: ModelCtx):
@@ -199,6 +216,8 @@ def _scan_layers(body, stacked, h, remat: bool):
 
 def embed_tokens(cfg, xp, tokens, prefix_embeds):
     h = jnp.take(xp["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
     if prefix_embeds is not None and cfg.n_prefix_embeds:
         npfx = prefix_embeds.shape[1]
         h = jnp.concatenate([prefix_embeds.astype(h.dtype), h[:, npfx:]], axis=1)
@@ -245,6 +264,23 @@ def features(cfg: ArchConfig, xp, batch: Dict[str, jax.Array],
     elif fam == "hybrid":
         h = _hybrid_seq(cfg, xp, h, ctx, pos, remat)
 
+    elif fam == "hybrid_moe":
+        for kind, lo, n, j0 in layer_runs(cfg):
+            def body(carry, lp, kind=kind):
+                if kind == "mamba":
+                    hn = rmsnorm(carry, lp["m"]["ln"], cfg.norm_eps)
+                    y, _ = ssm_lib.mamba2_seq(cfg, lp["m"], hn,
+                                              chunk=ctx.ssm_chunk)
+                    carry = residual(cfg, carry, y)
+                else:
+                    carry, _ = _attn_block(cfg, lp["m"], carry, ctx, pos=pos)
+                carry, _ = moe_block(cfg, lp["f"], carry)
+                return shard_act(carry, ("batch", "seq", "act_embed"),
+                                 ctx.rules)
+            stack = {"m": jax.tree.map(lambda a: a[j0:j0 + n], xp[kind]),
+                     "f": jax.tree.map(lambda a: a[lo:lo + n], xp["layers"])}
+            h = _scan_layers(body, stack, h, remat)
+
     elif fam == "encdec":
         enc_out = encoder_forward(cfg, xp, batch["enc_embeds"], ctx)
 
@@ -286,10 +322,48 @@ def _hybrid_seq(cfg, xp, h, ctx, pos, remat):
     return h
 
 
-def head_logits(cfg: ArchConfig, yp, feats: jax.Array) -> jax.Array:
+def layer_runs(cfg: ArchConfig):
+    """hybrid_moe's pattern as runs of consecutive layers of one kind:
+    [(kind, first layer, count, index of its first layer in that kind's
+    stack)], kind "mamba" or "attn"."""
+    runs, seen = [], {"mamba": 0, "attn": 0}
+    for i, t in enumerate(cfg.layer_types):
+        kind = "mamba" if t == "mamba" else "attn"
+        if runs and runs[-1][0] == kind:
+            k, lo, n, j0 = runs[-1]
+            runs[-1] = (k, lo, n + 1, j0)
+        else:
+            runs.append((kind, i, 1, seen[kind]))
+        seen[kind] += 1
+    return runs
+
+
+def moe_block(cfg: ArchConfig, p, h, layer=None):
+    """hybrid_moe's MLP: the held experts' part plus the shared expert, on
+    the normed stream, as one residual branch. Returns (h', tokens routed
+    to each held expert). With ``layer`` (serving), ``p`` is the whole
+    stack and this the layer's index (see ``moe.held_experts``)."""
+    if layer is not None:
+        p = {k: a if k.startswith("we_") else a[layer] for k, a in p.items()}
+    hn = rmsnorm(h, p["ln_mlp"], cfg.norm_eps)
+    routed, sizes = moe_lib.held_experts(cfg, p, hn, layer)
+    return residual(cfg, h, routed + moe_lib.shared_expert(p, hn)), sizes
+
+
+def head_logits(cfg: ArchConfig, yp, feats: jax.Array,
+                embed: Optional[jax.Array] = None) -> jax.Array:
+    """Logits of the final-normed features; a tied model passes its
+    embedding table as ``embed``."""
     h = rmsnorm(feats, yp["final_norm"], cfg.norm_eps)
-    return jnp.einsum("bsd,dv->bsv", h, yp["head"])
+    if cfg.tie_embeddings:
+        out = jnp.einsum("bsd,vd->bsv", h, embed)
+    else:
+        out = jnp.einsum("bsd,dv->bsv", h, yp["head"])
+    if cfg.logits_scaling != 1.0:
+        out = out / jnp.asarray(cfg.logits_scaling, out.dtype)
+    return out
 
 
 def forward(cfg, params, batch, ctx: ModelCtx) -> jax.Array:
-    return head_logits(cfg, params["y"], features(cfg, params["x"], batch, ctx))
+    return head_logits(cfg, params["y"], features(cfg, params["x"], batch, ctx),
+                       params["x"]["embed"])

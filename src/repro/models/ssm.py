@@ -260,7 +260,11 @@ def mamba2_decode(cfg: ArchConfig, p: Dict[str, jax.Array], x: jax.Array,
     z, xi, xh, Bc, Cc, dt, conv_state = _mamba2_project(cfg, p, x, conv_state)
     A = -jnp.exp(p["A_log"])
     a = jnp.exp(dt[:, 0] * A)                                    # [B,H]
-    upd = jnp.einsum("bh,bhp,bn->bhpn", dt[:, 0], xh[:, 0], Bc[:, 0])
-    h = a[..., None, None] * h + upd
-    y = jnp.einsum("bhpn,bn->bhp", h, Cc[:, 0])[:, None]         # [B,1,H,P]
-    return _mamba2_out(cfg, p, y, xh, dt, z, x.dtype), (h, conv_state)
+    dx = dt[:, 0, :, None] * xh[:, 0]                            # [B,H,P]
+    # y = C.(a h + dx B) from the old state, so that the new state has one
+    # consumer (the caller's in-place update) and is never staged whole
+    y = (a[..., None] * jnp.einsum("bhpn,bn->bhp", h, Cc[:, 0])
+         + dx * jnp.einsum("bn,bn->b", Bc[:, 0], Cc[:, 0])[:, None, None])
+    h = a[..., None, None] * h + dx[..., None] * Bc[:, 0, None, None, :]
+    return (_mamba2_out(cfg, p, y[:, None], xh, dt, z, x.dtype),
+            (h, conv_state))

@@ -15,6 +15,10 @@ per (token, head) and laid out heads-before-positions on the way into the
 pool (``models.decode`` module docstring), and decode attends through
 either the XLA reference dequant or the fused Pallas kernel
 (``kv_kernel="pallas"``; ``"interpret"`` runs the same kernel on CPU).
+A hybrid_moe pool holds the int8 K/V of its attention layers beside the
+float32 mamba state of the others; the row's scatter writes both, so a
+refilled slot starts from its own prompt's state. Its decode also counts
+the tokens routed to each held expert, read at the tick's token sync.
 """
 from __future__ import annotations
 
@@ -32,7 +36,7 @@ from repro.fed.serve import build_serve_fns
 from repro.models.decode import quantize_cache
 from repro.obs.telemetry import NULL
 
-QUANT_FAMILIES = ("dense", "vlm", "moe", "encdec")
+QUANT_FAMILIES = ("dense", "vlm", "moe", "encdec", "hybrid_moe")
 KV_KERNELS = ("auto", "xla", "pallas", "interpret")
 
 
@@ -254,7 +258,7 @@ class Engine:
         ``serve.retire`` loop."""
         tele = self.tele
         completed: List[Completion] = []
-        admitted = 0
+        admitted, routed = 0, None
         with tele.span("serve.tick"):
             while self._queue and self._free:
                 with tele.span("serve.admit"):
@@ -265,13 +269,16 @@ class Engine:
                       if self._occupant[s] is not None]
             if active:
                 with tele.span("serve.decode"):
-                    logits, self._pool = self._decode(
+                    logits, pool = self._decode(
                         self.params, self._pool,
                         jnp.asarray(self._last_tok[:, None]),
                         jnp.asarray(np.maximum(self._pos, 1)))
+                    routed = pool.pop("expert_tokens", None)
+                    self._pool = pool
                     ids = self._argmax(logits)
                     with tele.span("serve.sync"):
-                        nxt = np.asarray(jax.device_get(ids))
+                        nxt, routed = jax.device_get((ids, routed))
+                        nxt = np.asarray(nxt)
                 with tele.span("serve.retire"):
                     for s in active:
                         tok = int(nxt[s])
@@ -286,9 +293,13 @@ class Engine:
                         elif self._pos[s] >= self.max_len:
                             self._retire(s, "capacity", completed)
             self._ticks += 1
+            load = {}
+            if routed is not None:
+                load = {"expert_tokens_max": int(routed.max()),
+                        "expert_tokens_mean": float(routed.mean())}
             tele.tick(self._ticks - 1, active=len(active),
                       admitted=admitted, completed=len(completed),
-                      queue_depth=len(self._queue))
+                      queue_depth=len(self._queue), **load)
         return completed
 
     def run(self, requests=None) -> List[Completion]:

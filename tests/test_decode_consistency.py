@@ -18,7 +18,8 @@ FAMS = ["qwen1.5-4b",        # dense (MHA, qkv bias)
         "falcon-mamba-7b",   # ssm
         "zamba2-1.2b",       # hybrid
         "qwen3-moe-30b-a3b", # moe
-        "whisper-tiny"]      # encdec
+        "whisper-tiny",      # encdec
+        "granite-4.0-h-small"]  # hybrid_moe
 
 
 def _cfg(arch_id):

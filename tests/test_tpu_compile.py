@@ -85,6 +85,7 @@ def test_dequantize_compiles(one_chip, n):
 @pytest.mark.parametrize("b,h,kv,s,dh", [
     (8, 6, 6, 256, 64),          # chip_smoke serve: whisper-tiny, 8 slots
     (8, 40, 8, 32768, 128),      # long cache
+    (64, 32, 8, 4096, 128),      # granite-4.0-h-small serving cell: G=4
 ])
 def test_quant_decode_compiles(one_chip, b, h, kv, s, dh):
     _compile(lambda q, k8, ks, v8, vs, pos: quant_decode_attention(
@@ -165,3 +166,41 @@ def test_int8_decode_program_keeps_pool_in_place(one_chip, n_layers, slots,
                 and op == "copy"]) <= 2
     assert [op for dt, n, op, _ in values
             if dt == "bf16" and n == cfg.d_model * cfg.d_ff] == []
+
+
+def test_hybrid_decode_program_keeps_state_and_experts_in_place(one_chip):
+    """The serve engine's int8 decode program for granite-4.0-h-small at
+    published widths (a Mamba, an attention and a Mamba layer; 9 of 72
+    experts held; 8 slots): the pool rides through the layer loops, so
+    the float32 Mamba state is copied whole at most once (the carry) and
+    no value the size of one layer's state is made in HBM; each ragged
+    expert matmul reads the stacked experts in place, so no value the size
+    of one layer's held experts is made either."""
+    cfg = get_arch("granite-4.0-h-small")
+    cfg = dataclasses.replace(
+        cfg, n_layers=3, layer_types=("mamba", "attention", "mamba"),
+        moe=dataclasses.replace(cfg.moe, n_experts=9, n_routed=72))
+    slots, max_len = 8, 512
+    fns = build_serve_fns(cfg, ShapeConfig("serve_decode", max_len, slots,
+                                           "decode"),
+                          None, kv_quant=True, kv_kernel="pallas")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+    text = fns["decode"].lower(
+        on_chip(fns["params_abs"]), on_chip(fns["cache_abs"]),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    state = math.prod(fns["cache_abs"]["h"].shape)
+    e = cfg.moe
+    values = list(_hbm_values(text))
+    assert len([op for dt, n, op, _ in values
+                if dt == "f32" and n == state and op == "copy"]) <= 1
+    assert [op for dt, n, op, _ in values
+            if dt == "f32" and n == state // 2] == []
+    assert [op for dt, n, op, _ in values if dt == "bf16" and n ==
+            e.n_experts * cfg.d_model * e.d_ff_expert and op != "bitcast"] \
+        == []
